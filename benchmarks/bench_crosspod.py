@@ -4,20 +4,22 @@ Lowers both schedules on a (2,4,4)-host mesh, parses the partitioned HLO
 and reports collective wire bytes + the analytic DCN split for the
 production (2,16,16) mesh. This is the paper's Figure-2 message-count
 argument executed on real collectives.
+
+The mesh needs 32 devices, which only the CPU backend can fake, so the
+sweep runs as its own process and sets ``XLA_FLAGS`` before JAX is
+imported:
+
+  PYTHONPATH=src JAX_PLATFORMS=cpu python -m benchmarks.bench_crosspod
 """
 
 from __future__ import annotations
 
+import os
+
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count=32"
 
 
 def main():
-    # needs its own device count: run under dryrun-style env if top-level
-    import os
-    if "xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                                   + " --xla_force_host_platform_device_"
-                                     "count=32")
     import jax
     import jax.numpy as jnp
 
@@ -53,4 +55,8 @@ def main():
 
 
 if __name__ == "__main__":
+    if "xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                                   + " " + HOST_DEVICES_FLAG)
     main()

@@ -1,6 +1,8 @@
-"""Kernel micro-benchmarks (interpret mode on CPU => correctness-scale
-timings; the real perf story is the roofline VMEM analysis in
-EXPERIMENTS.md). Reports us/call for kernel vs pure-jnp oracle."""
+"""Kernel micro-benchmarks: us/call for each Pallas kernel vs its pure-jnp
+oracle. The kernels run as the platform picks
+(``repro.kernels.ops.default_interpret``): compiled on a TPU, through the
+Pallas interpreter elsewhere, where the times say nothing about the
+chip. Each line names the mode it ran in."""
 
 from __future__ import annotations
 
@@ -10,12 +12,18 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import flash_attention, quack_scan, rwkv6_chunked
+from repro.kernels.ops import default_interpret
 from repro.kernels.ref import (mha_reference, quack_reference,
                                rwkv6_reference)
 
 
+def mode() -> str:
+    """How the kernels run on this platform: "interpreted" or "compiled"."""
+    return "interpreted" if default_interpret() else "compiled"
+
+
 def _time(fn, *args, reps=3):
-    fn(*args)  # compile
+    jax.block_until_ready(fn(*args))  # compile
     t0 = time.time()
     for _ in range(reps):
         jax.block_until_ready(fn(*args))
@@ -23,6 +31,7 @@ def _time(fn, *args, reps=3):
 
 
 def main():
+    tag = mode()
     rng = jax.random.PRNGKey(0)
     ks = jax.random.split(rng, 5)
 
@@ -32,7 +41,7 @@ def main():
     t_kern = _time(lambda *a: flash_attention(*a, causal=True, block_q=128,
                                               block_kv=128), q, k, v)
     t_ref = _time(lambda *a: mha_reference(*a, causal=True), q, k, v)
-    print(f"flash_attention_interp,{t_kern:.0f},ref_us={t_ref:.0f}")
+    print(f"flash_attention_{tag},{t_kern:.0f},ref_us={t_ref:.0f}")
 
     r = jax.random.normal(ks[0], (1, 2, 256, 32)) * 0.5
     kk = jax.random.normal(ks[1], (1, 2, 256, 32)) * 0.5
@@ -41,7 +50,7 @@ def main():
     u = jax.random.normal(ks[4], (2, 32)) * 0.5
     t_kern = _time(lambda *a: rwkv6_chunked(*a, chunk=128), r, kk, vv, w, u)
     t_ref = _time(lambda *a: rwkv6_reference(*a)[0], r, kk, vv, w, u)
-    print(f"rwkv6_chunked_interp,{t_kern:.0f},ref_us={t_ref:.0f}")
+    print(f"rwkv6_chunked_{tag},{t_kern:.0f},ref_us={t_ref:.0f}")
 
     claims = jax.random.bernoulli(ks[0], 0.6, (4, 16, 1024))
     comps = jax.random.bernoulli(ks[1], 0.2, (4, 16, 1024))
@@ -50,7 +59,7 @@ def main():
                    claims, comps, stakes)
     t_ref = _time(lambda *a: quack_reference(*a, 5.0, 2.0),
                   claims, comps, stakes)
-    print(f"quack_scan_interp,{t_kern:.0f},ref_us={t_ref:.0f}")
+    print(f"quack_scan_{tag},{t_kern:.0f},ref_us={t_ref:.0f}")
 
 
 if __name__ == "__main__":

@@ -22,6 +22,11 @@ by the run: delivery-latency histogram + bucketed p50/p95/p99, HWMs,
 event counters, and the host-span rollup with the drain-overlap ratio.
 List-shaped BENCH files are wrapped to ``{"rows": [...], "metrics":
 {...}}`` in that mode; without ``--obs`` their schema is unchanged.
+
+Every section runs in this one process, so on a chip host nothing else
+competes for the chip. The cross-pod collective sweep needs 32 virtual
+CPU devices, which a chip host cannot give, so it is not a section: run
+it on its own as ``python -m benchmarks.bench_crosspod``.
 """
 
 from __future__ import annotations
@@ -29,9 +34,10 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
+
+from repro.compile_cache import use_compile_cache
 
 
 def _timed(name, fn):
@@ -81,9 +87,12 @@ def thm1():
 
 
 def kernels():
+    import jax
+
     from benchmarks import bench_kernels as m
     m.main()
-    return "interpret-mode (see EXPERIMENTS.md roofline for TPU story)"
+    dev = jax.devices()[0]
+    return f"{m.mode()}_on_{dev.platform}({dev.device_kind})"
 
 
 def windowed():
@@ -166,21 +175,6 @@ def adversary():
             f"reconfig_warm={rec['warm_s']:.2f}s,extra_traces={extra}")
 
 
-def crosspod():
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=32"
-    env.setdefault("PYTHONPATH", "src")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_crosspod"],
-        env=env, capture_output=True, text=True, timeout=900)
-    print(out.stdout, end="")
-    if out.returncode != 0:
-        print(out.stderr[-1000:])
-        return "FAILED"
-    lines = [l for l in out.stdout.splitlines() if l.startswith("picsou,")]
-    return f"dcn_reduction={lines[-1].split(',')[-1]}x" if lines else "n/a"
-
-
 # section name -> (harness fn, BENCH json the sweep is expected to emit)
 TABLES = (("fig8_scalability", fig8, None),
           ("fig9_failures_stakes", fig9, None),
@@ -192,8 +186,7 @@ TABLES = (("fig8_scalability", fig8, None),
           ("replay_whatif", replay, "BENCH_replay.json"),
           ("stream", stream, "BENCH_stream.json"),
           ("adversary", adversary, "BENCH_adversary.json"),
-          ("kernels", kernels, None),
-          ("crosspod_collectives", crosspod, None))
+          ("kernels", kernels, None))
 
 # regression gate knobs for --compare: a section regresses when its wall
 # time grows by more than REGRESSION_FRAC over the prior summary AND the
@@ -311,6 +304,7 @@ def main(argv=None) -> int:
                          "on a >15%% warm wall-time regression in any "
                          "section (small absolute deltas are ignored)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     only = set(args.only.split(",")) if args.only else None
     tables = [t for t in TABLES if only is None or t[0] in only]

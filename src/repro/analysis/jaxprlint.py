@@ -20,6 +20,10 @@ jaxpr and on the lowered module:
   argument is donated on backends where XLA implements aliasing (the
   CPU client ignores donation, so there it is reported as info, not a
   violation).
+* **device constants** — a ``jax.Array`` closed over by the program.
+  Lowering embeds it as a literal, which fetches it back to the host:
+  a hidden device->host transfer per compile that the ``debug_checks``
+  transfer guard refuses on a TPU. Host constants belong in numpy.
 * **dispatch estimates** — the exact number of device dispatches the
   host loop will issue for a (steps, chunk_steps, K) plan, computed by
   replicating the loop's span arithmetic; the sanitizer's runtime
@@ -59,7 +63,8 @@ class ProgramAudit:
     undonated_large: Tuple[int, ...]       # large argnums not donated
     donation_enforced: bool                # backend implements aliasing
     lowered_callback_calls: int            # custom_call cross-check
-    notes: str = ""
+    device_constants: Tuple[Tuple[int, ...], ...] = ()  # closed-over
+    notes: str = ""                                     # jax.Array shapes
 
     @property
     def ok(self) -> bool:
@@ -67,6 +72,7 @@ class ProgramAudit:
         wherever the backend implements it."""
         return (not self.host_callbacks and not self.widenings
                 and self.lowered_callback_calls == 0
+                and not self.device_constants
                 and (not self.donation_enforced
                      or not self.undonated_large))
 
@@ -80,6 +86,9 @@ class ProgramAudit:
                        f"callback custom-calls in the lowered module")
         for w in self.widenings:
             out.append(f"{self.name}: dtype widening {w}")
+        for shape in self.device_constants:
+            out.append(f"{self.name}: device-array constant of shape "
+                       f"{shape} (lowering fetches it to the host)")
         if self.donation_enforced and self.undonated_large:
             out.append(f"{self.name}: large undonated args "
                        f"{list(self.undonated_large)}")
@@ -106,6 +115,17 @@ def iter_eqns(jaxpr):
                     yield from iter_eqns(sub)
                 elif hasattr(item, "eqns"):      # raw Jaxpr
                     yield from iter_eqns(item)
+
+
+def _device_constants(closed) -> List[Tuple[int, ...]]:
+    """Shapes of the ``jax.Array`` constants of ``closed`` and of every
+    closed sub-jaxpr (scan/cond/pjit bodies)."""
+    consts = list(closed.consts)
+    for eqn in iter_eqns(closed.jaxpr):        # already recursive
+        for val in eqn.params.values():
+            for item in (val if isinstance(val, (tuple, list)) else (val,)):
+                consts += getattr(item, "consts", ())
+    return [tuple(c.shape) for c in consts if isinstance(c, jax.Array)]
 
 
 def _tree_bytes(tree) -> int:
@@ -152,7 +172,8 @@ def audit_callable(fn, args: Sequence[Any], name: str,
         arg_bytes=arg_bytes, donated_args=tuple(donate),
         undonated_large=undonated,
         donation_enforced=jax.default_backend() != "cpu",
-        lowered_callback_calls=callback_calls)
+        lowered_callback_calls=callback_calls,
+        device_constants=tuple(_device_constants(closed)))
 
 
 def estimate_dispatches(steps: int, chunk_steps: int, k: int) -> int:
@@ -224,7 +245,7 @@ def audit_engine(m: int = 64, window_slots: int = 16,
 
     from ..core.simulator import (_build_chunk, _build_run, _donate_state,
                                   _fail_arrays, _init_state, _neutral,
-                                  _max_msg_by_round)
+                                  superchunk_program)
 
     spec = _tiny_spec(m, window_slots, chunk_steps, superchunk)
     nspec = _neutral(spec)
@@ -257,19 +278,16 @@ def audit_engine(m: int = 64, window_slots: int = 16,
                           .lower(bfails, bstate, t0).as_text()
                           if with_lowered else None)))
 
-    # the superchunk program, staged through the real cached constructor
-    from ..core.simulator import _compiled_batch_superchunk
-    sc = _compiled_batch_superchunk(cspec, w, c, k)
-    dispatched_by = _max_msg_by_round(spec)
-    needs = jnp.asarray(
-        np.minimum(dispatched_by[c - 1::c][:k], spec.m).astype(np.int32))
-    if needs.shape[0] < k:                      # short plans: pad needs
-        needs = jnp.concatenate(
-            [needs, jnp.full((k - needs.shape[0],), spec.m, jnp.int32)])
-    audits.append(audit_callable(
-        sc, (bfails, bstate, t0, needs), "superchunk", donate=donate,
-        lowered_text=(sc.lower(bfails, bstate, t0, needs).as_text()
-                      if with_lowered else None)))
+    # the superchunk programs, staged through the real cached
+    # constructor at the shapes the host loop calls them with
+    def audit_superchunk(sc_spec, name):
+        sc, sc_args = superchunk_program(sc_spec)
+        audits.append(audit_callable(
+            sc, sc_args, name, donate=donate,
+            lowered_text=(sc.lower(*sc_args).as_text()
+                          if with_lowered else None)))
+
+    audit_superchunk(spec, "superchunk")
 
     # the observability fabric's programs: same constructors with
     # collect_metrics on, scan carry = (SimState, MetricsCarry)
@@ -286,12 +304,8 @@ def audit_engine(m: int = 64, window_slots: int = 16,
         lowered_text=(jax.jit(fn_obs, donate_argnums=donate)
                       .lower(bfails, bcarry, t0).as_text()
                       if with_lowered else None)))
-    sc_obs = _compiled_batch_superchunk(mspec, w, c, k)
-    audits.append(audit_callable(
-        sc_obs, (bfails, bcarry, t0, needs), "superchunk_obs",
-        donate=donate,
-        lowered_text=(sc_obs.lower(bfails, bcarry, t0, needs).as_text()
-                      if with_lowered else None)))
+    audit_superchunk(dc.replace(spec, collect_metrics=True),
+                     "superchunk_obs")
 
     # horizon-mode (streaming-session) programs: the same chunk /
     # superchunk constructors, staged at a *stream* spec — an
@@ -308,8 +322,7 @@ def audit_engine(m: int = 64, window_slots: int = 16,
                   chunk_steps=chunk_steps, superchunk=superchunk),
         ArrivalProcess(kind="constant", rate=4.0), horizon=m)
     s_cspec = dc.replace(_neutral(sspec), steps=0)
-    sw, s_c, s_k = (sspec.window_slots, sspec.chunk_steps,
-                    sspec.superchunk)
+    sw, s_c = sspec.window_slots, sspec.chunk_steps
     sfails = _fail_arrays(sspec)
     sbfails = jax.tree_util.tree_map(lambda x: jnp.broadcast_to(
         x, (1,) + jnp.shape(x)), sfails)
@@ -328,20 +341,7 @@ def audit_engine(m: int = 64, window_slots: int = 16,
         lowered_text=(jax.jit(fn_stream, donate_argnums=donate)
                       .lower(sbfails, sbcarry, t0).as_text()
                       if with_lowered else None)))
-    s_by = _max_msg_by_round(sspec)
-    s_needs = jnp.asarray(np.minimum(
-        s_by[s_c - 1::s_c][:s_k], sspec.m).astype(np.int32))
-    if s_needs.shape[0] < s_k:
-        s_needs = jnp.concatenate(
-            [s_needs,
-             jnp.full((s_k - s_needs.shape[0],), sspec.m, jnp.int32)])
-    sc_stream = _compiled_batch_superchunk(s_cspec, sw, s_c, s_k)
-    audits.append(audit_callable(
-        sc_stream, (sbfails, sbcarry, t0, s_needs), "superchunk_stream",
-        donate=donate,
-        lowered_text=(sc_stream.lower(sbfails, sbcarry, t0,
-                                      s_needs).as_text()
-                      if with_lowered else None)))
+    audit_superchunk(sspec, "superchunk_stream")
 
     n_chunks = -(-spec.steps // c)
     estimates = []

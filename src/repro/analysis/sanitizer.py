@@ -235,6 +235,14 @@ def _counters():
             sim.chunk_trace_count())
 
 
+def _device_to_host_guard():
+    """JAX's own device->host transfer guard, refusing implicit
+    transfers wherever the backend enforces it (not the CPU client,
+    whose buffers the host shares)."""
+    return jax.transfer_guard_device_to_host(
+        "disallow" if jax.default_backend() != "cpu" else "allow")
+
+
 @contextlib.contextmanager
 def sanitized(contract: Optional[DispatchContract] = None, *,
               check: bool = True) -> Iterator[SanitizerReport]:
@@ -250,9 +258,7 @@ def sanitized(contract: Optional[DispatchContract] = None, *,
     d0, s0, t0 = _counters()
     sink = _install()
     try:
-        with jax.transfer_guard_device_to_host(
-                "disallow" if jax.default_backend() != "cpu"
-                else "allow"):
+        with _device_to_host_guard():
             yield report
     finally:
         _uninstall(sink)
@@ -276,13 +282,15 @@ def engine_guard() -> Iterator[None]:
 
     Wrapped around ``_run_windowed_batch`` when
     ``SimConfig.debug_checks`` is set — any implicit device->host
-    materialization inside the drain/checkpoint path raises
-    immediately, with no dispatch ceiling (callers compose their own
-    :func:`sanitized` for that).
+    materialization inside the drain/checkpoint path raises, with no
+    dispatch ceiling (callers compose their own :func:`sanitized` for
+    that). Off the CPU, JAX's transfer guard refuses such a transfer
+    on the spot; the numpy interposition reports it when the run ends.
     """
     sink = _install()
     try:
-        yield
+        with _device_to_host_guard():
+            yield
     finally:
         _uninstall(sink)
     if sink:

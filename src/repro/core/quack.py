@@ -54,7 +54,8 @@ def stake_quorum_bitmap(claims: jnp.ndarray, complaints: jnp.ndarray,
 
     ``use_pallas`` routes the reduction through the Pallas TPU kernel
     (``kernels.quack_scan`` — MXU stake matmul + cross-block prefix
-    carry; interpret mode off-TPU via ``kernels.ops.default_interpret``).
+    carry; compiled on TPU, interpret mode elsewhere via
+    ``kernels.ops.default_interpret``).
     Stakes are small integers in every configuration the protocol uses,
     so the float32 quorum sums are exact and the two paths agree
     bit-for-bit (``tests/test_pipeline.py``).
@@ -66,28 +67,28 @@ def stake_quorum_bitmap(claims: jnp.ndarray, complaints: jnp.ndarray,
     """
     stakes = stakes.astype(jnp.float32)
     if use_pallas:
-        from ..kernels.ops import default_interpret, quack_scan
-        from ..kernels.quack_scan import BLOCK_W
-        # the kernel streams W in blocks of min(BLOCK_W, W) and needs
-        # the width to be a block multiple; window widths are arbitrary
-        # (auto sizing rounds to 64, growth doubles, dense fallback uses
-        # M), so pad with never-claimed columns — they sit beyond every
+        from ..kernels.quack_scan import BLOCK_W, quack_scan
+        # the kernel streams W in blocks of BLOCK_W, or of all of W
+        # below that, and the chip's tiling wants lane-dense blocks (a
+        # multiple of 128 columns); window widths are arbitrary (auto
+        # sizing rounds to 64, growth doubles, dense fallback uses M),
+        # so pad with never-claimed columns — they sit beyond every
         # real column, leaving the quorum bitmaps and the contiguous
         # quacked prefix untouched — and slice back.
         w = claims.shape[-1]
-        pad = (-w) % min(BLOCK_W, w)
+        pad = (-w) % min(BLOCK_W, -(-w // 128) * 128)
         if pad:
             ext = jnp.zeros(claims.shape[:-1] + (pad,), dtype=bool)
             claims = jnp.concatenate([claims, ext], axis=-1)
             complaints = jnp.concatenate([complaints, ext], axis=-1)
         # thresholds stay jnp values (possibly traced — stake re-weight
         # swaps feed them through FailArrays): the kernel takes them as
-        # (1, 1) scalar blocks, so a traced threshold costs no recompile
+        # an SMEM operand, so a traced threshold costs no recompile
         quacked, lost, prefix = quack_scan(
             claims, complaints, stakes,
             jnp.asarray(quack_thresh, dtype=jnp.float32),
             jnp.asarray(dup_thresh, dtype=jnp.float32), block_w=BLOCK_W,
-            interpret=default_interpret(), compute_lost=need_lost)
+            compute_lost=need_lost)
         return (quacked[..., :w],
                 None if lost is None else lost[..., :w],
                 prefix.astype(jnp.int32))

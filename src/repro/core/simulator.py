@@ -844,9 +844,11 @@ def _init_state(spec: SimSpec, w: int) -> SimState:
 
 
 def _sched_arrays(spec: SimSpec):
-    return (jnp.asarray(spec.orig_sender, dtype=jnp.int32),
-            jnp.asarray(spec.orig_recv, dtype=jnp.int32),
-            jnp.asarray(spec.orig_step, dtype=jnp.int32))
+    # host (numpy) constants: a device array closed over by a jitted
+    # program is fetched back to the host when the program is lowered,
+    # which the debug_checks transfer guard refuses off the CPU
+    return tuple(np.asarray(a, dtype=np.int32) for a in
+                 (spec.orig_sender, spec.orig_recv, spec.orig_step))
 
 
 def _build_run(nspec: SimSpec):
@@ -862,7 +864,8 @@ def _build_run(nspec: SimSpec):
     collect = nspec.collect_metrics
 
     def run(fail: FailArrays):
-        step = _protocol_step(nspec, fail, sched_full, 0, nspec.m)
+        sched = tuple(jnp.asarray(a) for a in sched_full)
+        step = _protocol_step(nspec, fail, sched, 0, nspec.m)
         state0 = _init_state(nspec, nspec.m)
         ts = jnp.arange(nspec.steps, dtype=jnp.int32)
         if not collect:
@@ -980,9 +983,9 @@ def _build_chunk(nspec: SimSpec, w_slots: int, chunk_len: int, rotate: bool):
     osend, orecv, ostep = (np.asarray(a) for a in
                            (nspec.orig_sender, nspec.orig_recv,
                             nspec.orig_step))
-    pad = lambda a, fill: jnp.asarray(
-        np.concatenate([a, np.full(w_slots, fill, dtype=a.dtype)]),
-        dtype=jnp.int32)
+    # numpy, not device, constants (see _sched_arrays)
+    pad = lambda a, fill: np.concatenate(
+        [a, np.full(w_slots, fill, dtype=a.dtype)]).astype(np.int32)
     osend_p, orecv_p = pad(osend, 0), pad(orecv, 0)
     ostep_p = pad(np.minimum(ostep, _NEVER_STEP), _NEVER_STEP)
     collect = nspec.collect_metrics
@@ -1128,6 +1131,30 @@ def _compiled_batch_superchunk(nspec: SimSpec, w_slots: int,
         return carry0, ms, queues, oks
 
     return jax.jit(superchunk, donate_argnums=_donate_state())
+
+
+def superchunk_program(spec: SimSpec, lanes: int = 1):
+    """The fused superchunk program the engine dispatches for ``lanes``
+    copies of windowed ``spec`` at its initial window, with abstract
+    arguments of the shapes it is called with. ``program.lower(*args)``
+    stages exactly what a run compiles, without running anything — for
+    compile-only checks and for reading the compiled program's text."""
+    nspec = _neutral(spec)
+    w = spec.window_slots
+    c, k = max(spec.chunk_steps, 1), max(spec.superchunk, 1)
+    program = _compiled_batch_superchunk(
+        dataclasses.replace(nspec, steps=0), w, c, k)
+
+    def init():
+        carry = _init_state(nspec, w)
+        if spec.collect_metrics:
+            carry = (carry, init_metrics_carry(w))
+        return _fail_arrays(spec), carry
+
+    lane = lambda a: jax.ShapeDtypeStruct((lanes,) + a.shape, a.dtype)
+    fails, carry = jax.tree_util.tree_map(lane, jax.eval_shape(init))
+    return program, (fails, carry, jax.ShapeDtypeStruct((), jnp.int32),
+                     jax.ShapeDtypeStruct((k,), jnp.int32))
 
 
 # host materialization / width migration are the shared snapshot

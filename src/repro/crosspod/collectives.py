@@ -93,14 +93,9 @@ def _is_arr(x):
 
 
 def _shard_map(fn, mesh, spec, tree):
-    try:
-        from jax import shard_map as _sm  # jax >= 0.6
-        kw = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        kw = {"check_rep": False}
     specs = jax.tree_util.tree_map(lambda _: spec, tree, is_leaf=_is_arr)
-    return _sm(fn, mesh=mesh, in_specs=(specs,), out_specs=specs, **kw)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(specs,), out_specs=specs,
+                         check_vma=False)
 
 
 def dcn_bytes_analytic(n_bytes: float, mesh_shape: Dict[str, int],

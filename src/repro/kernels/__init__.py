@@ -7,15 +7,6 @@ rwkv6_scan           | RWKV6 data-dependent recurrence | ref.rwkv6_reference
 quack_scan           | QUACK quorum aggregation (S4)   | ref.quack_reference
 """
 
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed pltpu.CompilerParams <-> TPUCompilerParams across releases;
-# alias whichever spelling this jax lacks so the kernels work on both.
-if not hasattr(_pltpu, "CompilerParams"):
-    _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-elif not hasattr(_pltpu, "TPUCompilerParams"):
-    _pltpu.TPUCompilerParams = _pltpu.CompilerParams
-
 from . import ref
 from .ops import flash_attention, quack_scan, rwkv6_chunked
 

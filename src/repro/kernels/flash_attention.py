@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -67,16 +68,26 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_kv", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_kv: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B,H,Sq,D); k,v: (B,KV,Skv,D) -> (B,H,Sq,D).
 
     Sq and Skv must be multiples of the block sizes; D should be a
     multiple of 128 for MXU alignment (any D works in interpret mode).
+    ``interpret=None`` runs compiled on TPU and interpreted elsewhere
+    (``kernels.ops.default_interpret``).
     """
+    from .ops import resolve_interpret
+    return _flash_attention(q, k, v, causal=causal, window=window,
+                            block_q=block_q, block_kv=block_kv,
+                            interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
+                                             "block_kv", "interpret"))
+def _flash_attention(q, k, v, *, causal: bool, window: int, block_q: int,
+                     block_kv: int, interpret: bool):
     b, h, sq, d = q.shape
     _, n_kv, skv, _ = k.shape
     g = h // n_kv

@@ -1,12 +1,14 @@
-"""Jitted public wrappers for the Pallas kernels.
+"""Public entry points of the Pallas kernels and their platform choice.
 
-On this CPU container the kernels execute in interpret mode (the kernel
-body runs in Python via the Pallas interpreter — bit-faithful to the TPU
-algorithm); on a real TPU set ``interpret=False`` (ModelConfig.use_pallas
-flips the model's attention/rwkv paths onto these wrappers).
+Each kernel takes ``interpret=None`` by default, which resolves here:
+on a TPU the kernel runs compiled (Mosaic), on any other backend the
+Pallas interpreter runs the kernel body instead (bit-faithful to the
+TPU algorithm, slow). Passing ``interpret`` explicitly overrides that.
 """
 
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 
@@ -15,7 +17,7 @@ from .quack_scan import quack_scan
 from .rwkv6_scan import rwkv6_chunked
 
 __all__ = ["flash_attention", "rwkv6_chunked", "quack_scan",
-           "on_tpu", "default_interpret"]
+           "on_tpu", "default_interpret", "resolve_interpret"]
 
 
 def on_tpu() -> bool:
@@ -24,3 +26,8 @@ def on_tpu() -> bool:
 
 def default_interpret() -> bool:
     return not on_tpu()
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """A caller's ``interpret`` choice, or the platform's when None."""
+    return default_interpret() if interpret is None else bool(interpret)

@@ -19,6 +19,7 @@ Validated in interpret mode against ``ref.rwkv6_reference``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,14 +60,22 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_chunked(r, k, v, w, u, *, chunk: int = 128,
-                  interpret: bool = True):
+                  interpret: Optional[bool] = None):
     """r,k,v,w: (B,H,T,D); u: (H,D). Returns y: (B,H,T,D) float32.
 
     T must be a multiple of ``chunk``. The state stays in VMEM across
-    chunks (sequential minor grid dimension).
+    chunks (sequential minor grid dimension). ``interpret=None`` runs
+    compiled on TPU and interpreted elsewhere
+    (``kernels.ops.default_interpret``).
     """
+    from .ops import resolve_interpret
+    return _rwkv6_chunked(r, k, v, w, u, chunk=chunk,
+                          interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _rwkv6_chunked(r, k, v, w, u, *, chunk: int, interpret: bool):
     b, h, t, d = r.shape
     assert t % chunk == 0, (t, chunk)
     nc = t // chunk

@@ -34,6 +34,7 @@ import sys
 
 import numpy as np
 
+from ..compile_cache import use_compile_cache
 from ..core.simulator import chunk_dispatch_count, run_simulation
 from ..core.types import RSMConfig, SimConfig
 from ..obs.report import report_from_results, validate_chrome_trace
@@ -176,6 +177,7 @@ def main(argv=None) -> int:
                     help="artifact directory (report + live jsonl + "
                          "chrome trace)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.selftest:
         return selftest(args)

@@ -248,6 +248,24 @@ def test_audit_callable_detects_widening():
     assert clean.ok
 
 
+@pytest.mark.parametrize("host", [True, False])
+def test_audit_callable_detects_device_constant(host):
+    """A jax.Array closed over by a program (not a numpy constant) is
+    reported, in a scan body too: lowering fetches it to the host."""
+    table = np.arange(8, dtype=np.int32)
+    if not host:
+        table = jnp.asarray(table)
+
+    def lookup(xs):
+        return jax.lax.scan(
+            lambda c, x: (c + jnp.asarray(table)[x], x), 0, xs)
+
+    audit = audit_callable(lookup, (jnp.arange(4, dtype=jnp.int32),),
+                           "lookup")
+    assert audit.ok == host
+    assert audit.device_constants == (() if host else ((8,),))
+
+
 def test_estimate_matches_engine_span_arithmetic():
     # 42 full chunks at K=8: 5 spans of 8 + tail — measured 7 on the
     # real engine (test below keeps them honest against each other)
