@@ -91,7 +91,8 @@ __all__ = ["SimSpec", "SimResult", "FailArrays", "build_spec",
            "require_uniform_batch", "ChunkCheckpoint", "WindowGrowthEvent",
            "spec_failures", "spec_with_failures", "spec_with_quorum",
            "retire_safety_stakes_ok", "chunk_trace_count",
-           "chunk_dispatch_count", "host_sync_count"]
+           "chunk_dispatch_count", "chunk_launch_ahead_count",
+           "host_sync_count"]
 
 # plain Python ints, not jnp scalars: a module-level jnp call would
 # initialize the JAX backend at import time (analysis: import-time-jnp);
@@ -638,159 +639,176 @@ def _protocol_step(spec: SimSpec, fail: FailArrays, sched_w, base, w: int):
         alive_r = (fail.crash_r < 0) | (t < fail.crash_r)
 
         # (1) broadcasts queued last round land now ------------------------
-        bcast_sent = state.bcast_q & alive_r[:, None]
-        recv_from_bcast = jnp.einsum("jk,ji->ik", bcast_sent, reach) > 0
-        recv_has = state.recv_has | (recv_from_bcast & alive_r[:, None])
-        bcast_done = state.bcast_done | bcast_sent
+        with jax.named_scope("c3b_broadcast"):
+            bcast_sent = state.bcast_q & alive_r[:, None]
+            recv_from_bcast = jnp.einsum("jk,ji->ik", bcast_sent, reach) > 0
+            recv_has = state.recv_has | (recv_from_bcast & alive_r[:, None])
+            bcast_done = state.bcast_done | bcast_sent
 
         # (2) retransmission declaration + election (knowledge of t-1) -----
-        quacked_msg_prev, lost_prev, qprefix_prev = stake_quorum_bitmap(
-            state.known, state.repeat_c, stakes_r, fail.quack_thresh,
-            fail.dup_thresh, use_pallas=spec.use_pallas_quack)
-        # losses can only be declared for messages whose original dispatch
-        # already happened; under commit gating the dispatch bit (not the
-        # schedule round) is what proves that.
-        declared = lost_prev & state.orig_sent[None, :]
-        retry_new = state.retry + declared.astype(jnp.int32)
-        # Fig. 6: the a-th retransmission of k is sent by the a-th successor
-        # of the original sender: sender_new = (orig + #retransmit) mod n_s.
-        elected = (rs_seq[(abs_idx[None, :] + retry_new) % ls]
-                   == idx_s[:, None])
-        resend = (declared & elected & alive_s[:, None]
-                  & ~fail.byz_send_drop[:, None])
-        # clear complaint trackers where a loss was declared (fresh cycle)
-        complaint = jnp.where(declared[:, None, :], False, state.complaint)
-        repeat_c = jnp.where(declared[:, None, :], False, state.repeat_c)
-        re_target = rr_seq[(orig_recv[None, :] + retry_new) % lr]  # (n_s, W)
-        # adversary: an equivocating sender's retransmissions carry a
-        # payload conflicting with the original — receivers detect the
-        # mismatch and discard them wholesale (no store, no ack claim,
-        # no hq metadata heard); the wire copy still happened (metrics
-        # count `resend` itself) and the retry counter/rotation advance,
-        # so the election keeps rotating toward an honest retransmitter.
-        # Selective per-pair drops kill the copy in the network instead:
-        # same observable non-delivery, but scoped to (sender, receiver).
-        drop_re = jnp.take_along_axis(fail.drop_pair, re_target, axis=1)
-        resend_land = resend & ~fail.byz_equiv_send[:, None] & ~drop_re
+        with jax.named_scope("c3b_quorum"):
+            quacked_msg_prev, lost_prev, qprefix_prev = stake_quorum_bitmap(
+                state.known, state.repeat_c, stakes_r, fail.quack_thresh,
+                fail.dup_thresh, use_pallas=spec.use_pallas_quack)
+        with jax.named_scope("c3b_retransmit"):
+            # losses can only be declared for messages whose original
+            # dispatch already happened; under commit gating the dispatch
+            # bit (not the schedule round) is what proves that.
+            declared = lost_prev & state.orig_sent[None, :]
+            retry_new = state.retry + declared.astype(jnp.int32)
+            # Fig. 6: the a-th retransmission of k is sent by the a-th
+            # successor of the original sender:
+            # sender_new = (orig + #retransmit) mod n_s.
+            elected = (rs_seq[(abs_idx[None, :] + retry_new) % ls]
+                       == idx_s[:, None])
+            resend = (declared & elected & alive_s[:, None]
+                      & ~fail.byz_send_drop[:, None])
+            # clear complaint trackers where a loss was declared (fresh cycle)
+            complaint = jnp.where(declared[:, None, :], False, state.complaint)
+            repeat_c = jnp.where(declared[:, None, :], False, state.repeat_c)
+            re_target = rr_seq[(orig_recv[None, :] + retry_new)
+                               % lr]                          # (n_s, W)
+            # adversary: an equivocating sender's retransmissions carry a
+            # payload conflicting with the original — receivers detect the
+            # mismatch and discard them wholesale (no store, no ack claim,
+            # no hq metadata heard); the wire copy still happened (metrics
+            # count `resend` itself) and the retry counter/rotation advance,
+            # so the election keeps rotating toward an honest retransmitter.
+            # Selective per-pair drops kill the copy in the network instead:
+            # same observable non-delivery, but scoped to (sender, receiver).
+            drop_re = jnp.take_along_axis(fail.drop_pair, re_target, axis=1)
+            resend_land = resend & ~fail.byz_equiv_send[:, None] & ~drop_re
 
         # (3) original sends + landing --------------------------------------
-        # a message is due once its schedule round has passed AND its
-        # entry is committed on the source RSM (commit_floor gate); the
-        # dispatch attempt happens exactly once (orig_sent), whether or
-        # not the scheduled sender is still alive — matching the ungated
-        # semantics where a crashed sender's message is simply never sent.
-        due = ((orig_step <= t) & (abs_idx < fail.commit_floor)
-               & ~state.orig_sent)
-        orig_ok = (due & alive_s[orig_sender]
-                   & ~fail.byz_send_drop[orig_sender])
-        orig_sent = state.orig_sent | due
-        # selective drop of the original copy: the (orig sender, orig
-        # receiver) pair is dropped in the network after being sent
-        drop_o = fail.drop_pair[orig_sender, orig_recv]          # (W,)
-        orig_land = orig_ok & ~drop_o
-        s_orig = orig_land[None, :] & (orig_recv[None, :] == idx_r[:, None])
-        s_re = (jnp.einsum("lm,lim->im", resend_land.astype(jnp.int32),
-                           (re_target[:, None, :] == idx_r[None, :, None])
-                           .astype(jnp.int32)) > 0)
-        wire = s_orig | s_re                                   # (n_r, W)
-        land = wire & alive_r[:, None] & ~fail.byz_recv_drop[:, None]
-        recv_has = recv_has | land
-        bcast_q = land & ~bcast_done
-        deliver_now = (recv_has & honest_r[:, None]).any(axis=0)
-        deliver_time = jnp.where((state.deliver_time < 0) & deliver_now,
-                                 t, state.deliver_time)
+        with jax.named_scope("c3b_send"):
+            # a message is due once its schedule round has passed AND its
+            # entry is committed on the source RSM (commit_floor gate); the
+            # dispatch attempt happens exactly once (orig_sent), whether or
+            # not the scheduled sender is still alive — matching the ungated
+            # semantics where a crashed sender's message is simply never sent.
+            due = ((orig_step <= t) & (abs_idx < fail.commit_floor)
+                   & ~state.orig_sent)
+            orig_ok = (due & alive_s[orig_sender]
+                       & ~fail.byz_send_drop[orig_sender])
+            orig_sent = state.orig_sent | due
+            # selective drop of the original copy: the (orig sender, orig
+            # receiver) pair is dropped in the network after being sent
+            drop_o = fail.drop_pair[orig_sender, orig_recv]          # (W,)
+            orig_land = orig_ok & ~drop_o
+            s_orig = (orig_land[None, :]
+                      & (orig_recv[None, :] == idx_r[:, None]))
+        with jax.named_scope("c3b_retransmit"):
+            s_re = (jnp.einsum("lm,lim->im", resend_land.astype(jnp.int32),
+                               (re_target[:, None, :] == idx_r[None, :, None])
+                               .astype(jnp.int32)) > 0)
+        with jax.named_scope("c3b_send"):
+            wire = s_orig | s_re                                   # (n_r, W)
+            land = wire & alive_r[:, None] & ~fail.byz_recv_drop[:, None]
+            recv_has = recv_has | land
+            bcast_q = land & ~bcast_done
+            deliver_now = (recv_has & honest_r[:, None]).any(axis=0)
+            deliver_time = jnp.where((state.deliver_time < 0) & deliver_now,
+                                     t, state.deliver_time)
 
         # (3b) highest-quacked metadata rides on every landed data message:
         # a sender's current quacked prefix reaches every receiver it sent
         # anything to this round (constant-size piggyback, §4.3). Window
         # slots below `base` are all-quacked by the retirement rule, so the
         # absolute prefix is base + the in-window prefix.
-        qp_prev = base + qprefix_prev
-        e_lk = ((orig_sender[None, :] == idx_s[:, None])
-                & orig_land[None, :])                          # (n_s, W)
-        sent_orig_to = jnp.einsum("lk,ik->li", e_lk.astype(jnp.int32),
-                                  s_orig.astype(jnp.int32)) > 0
-        sent_re_to = jnp.einsum(
-            "lm,lim->li", resend_land.astype(jnp.int32),
-            (re_target[:, None, :] == idx_r[None, :, None]).astype(jnp.int32)
-        ) > 0
-        heard = (sent_orig_to | sent_re_to).T                  # (n_r, n_s)
-        # adversary: an hq-lying sender inflates its piggybacked prefix
-        # per receiver — receiver i hears min(true + adv + i, m), so no
-        # two receivers can cross-check the same claim (equivocation on
-        # the §4.3 metadata). The r_s+1 attestation quorum is the
-        # defence: a floor only forms where >= r_s+1 stake agrees, and
-        # at most r_s of it can be lying.
-        hq_lie = fail.byz_hq_advance                            # (n_s,)
-        hq_claim = jnp.where(
-            hq_lie[None, :] > 0,
-            jnp.minimum(qp_prev[None, :] + hq_lie[None, :]
-                        + idx_r[:, None], m),
-            qp_prev[None, :])                                   # (n_r, n_s)
-        hq_new = jnp.where(heard & alive_r[:, None], hq_claim, 0)
-        hq_reports = jnp.maximum(state.hq_reports, hq_new.astype(jnp.int32))
+        with jax.named_scope("c3b_hq"):
+            qp_prev = base + qprefix_prev
+            e_lk = ((orig_sender[None, :] == idx_s[:, None])
+                    & orig_land[None, :])                          # (n_s, W)
+            sent_orig_to = jnp.einsum("lk,ik->li", e_lk.astype(jnp.int32),
+                                      s_orig.astype(jnp.int32)) > 0
+        with jax.named_scope("c3b_retransmit"):
+            sent_re_to = jnp.einsum(
+                "lm,lim->li", resend_land.astype(jnp.int32),
+                (re_target[:, None, :] == idx_r[None, :, None])
+                .astype(jnp.int32)) > 0
+        with jax.named_scope("c3b_hq"):
+            heard = (sent_orig_to | sent_re_to).T                  # (n_r, n_s)
+            # adversary: an hq-lying sender inflates its piggybacked prefix
+            # per receiver — receiver i hears min(true + adv + i, m), so no
+            # two receivers can cross-check the same claim (equivocation on
+            # the §4.3 metadata). The r_s+1 attestation quorum is the
+            # defence: a floor only forms where >= r_s+1 stake agrees, and
+            # at most r_s of it can be lying.
+            hq_lie = fail.byz_hq_advance                            # (n_s,)
+            hq_claim = jnp.where(
+                hq_lie[None, :] > 0,
+                jnp.minimum(qp_prev[None, :] + hq_lie[None, :]
+                            + idx_r[:, None], m),
+                qp_prev[None, :])                               # (n_r, n_s)
+            hq_new = jnp.where(heard & alive_r[:, None], hq_claim, 0)
+            hq_reports = jnp.maximum(state.hq_reports,
+                                     hq_new.astype(jnp.int32))
 
         # (4) acknowledgements ---------------------------------------------
-        ack_floor = weighted_quorum_prefix(hq_reports, stakes_s,
-                                           fail.hq_thresh)
-        ack_floor = jnp.maximum(state.ack_floor, ack_floor)
-        eff = recv_has | (abs_idx[None, :] < ack_floor[:, None])
-        cum, claim, _known_mask = claim_bitmask(eff, phi, base, m)
-        miss = missing_below_horizon(eff, phi, base)
-        # Byzantine lies --------------------------------------------------
-        cum = jnp.where(fail.byz_ack_low, 0, cum)
-        cum = jnp.where(fail.byz_ack_advance > 0,
-                        jnp.minimum(cum + fail.byz_ack_advance, m), cum)
-        claim = jnp.where(fail.byz_ack_low[:, None], False, claim)
-        claim = jnp.where((fail.byz_ack_advance > 0)[:, None],
-                          abs_idx[None, :] < cum[:, None], claim)
-        miss = jnp.where(fail.byz_ack_low[:, None],
-                         abs_idx[None, :] < phi, miss)
-        miss = jnp.where((fail.byz_ack_advance > 0)[:, None], False, miss)
-        # the ack rotation: receiver j acks sender (j + t) mod n_s, so
-        # `upd` marks exactly the (sender, receiver) pairs whose ack
-        # state refreshes this round
-        tgt = (idx_r + t) % n_s                                  # (n_r,)
-        upd = (tgt[None, :] == idx_s[:, None]) & alive_r[None, :]  # (n_s,n_r)
-        # adversary: a stale-acking receiver replays its *previous* ack
-        # to this round's target verbatim — the cum counter, prefix
-        # claim and complaint list it last sent that sender (zero/empty
-        # before the first ack). A replayed QUACK is truthful-but-old:
-        # monotone claims can never fabricate receipt, but the frozen
-        # cum counter trips the duplicate-cum complaint at the sender,
-        # manufacturing loss suspicion and resend load (applied LAST so
-        # a stale lie freezes whatever lie the other masks produced).
-        stale = fail.byz_ack_stale                               # (n_r,)
-        prev_cum = jnp.maximum(
-            jnp.where(upd, state.last_cum, 0).sum(axis=0), 0)    # (n_r,)
-        prev_miss = jnp.where(upd[:, :, None], state.complaint,
-                              False).any(axis=0)                 # (n_r, W)
-        cum = jnp.where(stale, prev_cum, cum)
-        claim = jnp.where(stale[:, None],
-                          abs_idx[None, :] < prev_cum[:, None], claim)
-        miss = jnp.where(stale[:, None], prev_miss, miss)
-        # implicit duplicate-cum complaint: cum unchanged since last ack to
-        # the same sender => complain about index cum (if it exists).
-        dup_cum = (state.last_cum == cum[None, :])               # (n_s, n_r)
-        dup_complaint = (dup_cum[:, :, None]
-                         & (abs_idx[None, None, :] == cum[None, :, None])
-                         & (cum[None, :, None] < m))
-        new_complaint = miss[None, :, :] | dup_complaint         # (n_s,n_r,W)
-        known = state.known | (upd[:, :, None] & claim[None, :, :])
-        repeat_c = jnp.where(upd[:, :, None],
-                             repeat_c | (complaint & new_complaint), repeat_c)
-        complaint = jnp.where(upd[:, :, None], new_complaint, complaint)
-        last_cum = jnp.where(upd, cum[None, :], state.last_cum)
+        with jax.named_scope("c3b_ack"):
+            ack_floor = weighted_quorum_prefix(hq_reports, stakes_s,
+                                               fail.hq_thresh)
+            ack_floor = jnp.maximum(state.ack_floor, ack_floor)
+            eff = recv_has | (abs_idx[None, :] < ack_floor[:, None])
+            cum, claim, _known_mask = claim_bitmask(eff, phi, base, m)
+            miss = missing_below_horizon(eff, phi, base)
+            # Byzantine lies --------------------------------------------------
+            cum = jnp.where(fail.byz_ack_low, 0, cum)
+            cum = jnp.where(fail.byz_ack_advance > 0,
+                            jnp.minimum(cum + fail.byz_ack_advance, m), cum)
+            claim = jnp.where(fail.byz_ack_low[:, None], False, claim)
+            claim = jnp.where((fail.byz_ack_advance > 0)[:, None],
+                              abs_idx[None, :] < cum[:, None], claim)
+            miss = jnp.where(fail.byz_ack_low[:, None],
+                             abs_idx[None, :] < phi, miss)
+            miss = jnp.where((fail.byz_ack_advance > 0)[:, None], False, miss)
+            # the ack rotation: receiver j acks sender (j + t) mod n_s, so
+            # `upd` marks exactly the (sender, receiver) pairs whose ack
+            # state refreshes this round
+            tgt = (idx_r + t) % n_s                                  # (n_r,)
+            upd = ((tgt[None, :] == idx_s[:, None])
+                   & alive_r[None, :])                       # (n_s, n_r)
+            # adversary: a stale-acking receiver replays its *previous* ack
+            # to this round's target verbatim — the cum counter, prefix
+            # claim and complaint list it last sent that sender (zero/empty
+            # before the first ack). A replayed QUACK is truthful-but-old:
+            # monotone claims can never fabricate receipt, but the frozen
+            # cum counter trips the duplicate-cum complaint at the sender,
+            # manufacturing loss suspicion and resend load (applied LAST so
+            # a stale lie freezes whatever lie the other masks produced).
+            stale = fail.byz_ack_stale                               # (n_r,)
+            prev_cum = jnp.maximum(
+                jnp.where(upd, state.last_cum, 0).sum(axis=0), 0)    # (n_r,)
+            prev_miss = jnp.where(upd[:, :, None], state.complaint,
+                                  False).any(axis=0)                 # (n_r, W)
+            cum = jnp.where(stale, prev_cum, cum)
+            claim = jnp.where(stale[:, None],
+                              abs_idx[None, :] < prev_cum[:, None], claim)
+            miss = jnp.where(stale[:, None], prev_miss, miss)
+            # implicit duplicate-cum complaint: cum unchanged since last ack to
+            # the same sender => complain about index cum (if it exists).
+            dup_cum = (state.last_cum == cum[None, :])           # (n_s, n_r)
+            dup_complaint = (dup_cum[:, :, None]
+                             & (abs_idx[None, None, :] == cum[None, :, None])
+                             & (cum[None, :, None] < m))
+            new_complaint = miss[None, :, :] | dup_complaint   # (n_s,n_r,W)
+            known = state.known | (upd[:, :, None] & claim[None, :, :])
+            repeat_c = jnp.where(upd[:, :, None],
+                                 repeat_c | (complaint & new_complaint),
+                                 repeat_c)
+            complaint = jnp.where(upd[:, :, None], new_complaint, complaint)
+            last_cum = jnp.where(upd, cum[None, :], state.last_cum)
 
         # (5) QUACK bookkeeping --------------------------------------------
-        # the lost bitmap is unused here (loss declaration works on t-1
-        # knowledge, step 2), so the loss quorum is dropped at the call
-        quacked_msg, _, qprefix = stake_quorum_bitmap(
-            known, repeat_c, stakes_r, fail.quack_thresh,
-            fail.dup_thresh, use_pallas=spec.use_pallas_quack,
-            need_lost=False)
-        quack_time = jnp.where((state.quack_time < 0) & quacked_msg,
-                               t, state.quack_time)
+        with jax.named_scope("c3b_quorum"):
+            # the lost bitmap is unused here (loss declaration works on t-1
+            # knowledge, step 2), so the loss quorum is dropped at the call
+            quacked_msg, _, qprefix = stake_quorum_bitmap(
+                known, repeat_c, stakes_r, fail.quack_thresh,
+                fail.dup_thresh, use_pallas=spec.use_pallas_quack,
+                need_lost=False)
+            quack_time = jnp.where((state.quack_time < 0) & quacked_msg,
+                                   t, state.quack_time)
 
         new_state = SimState(
             recv_has=recv_has, bcast_q=bcast_q, bcast_done=bcast_done,
@@ -801,19 +819,20 @@ def _protocol_step(spec: SimSpec, fail: FailArrays, sched_w, base, w: int):
             ack_floor=ack_floor, base=state.base,
             retired_delivered=state.retired_delivered)
 
-        qp = base + qprefix
-        min_qp = jnp.min(jnp.where(honest_s, qp, _BIG))
-        metrics = StepMetrics(
-            cross_msgs=(orig_ok.sum() + resend.sum()).astype(jnp.int32),
-            intra_msgs=jnp.einsum("jk,j->", bcast_sent.astype(jnp.int32),
-                                  reach.sum(axis=1).astype(jnp.int32)
-                                  ).astype(jnp.int32),
-            resends=resend.sum().astype(jnp.int32),
-            acks=alive_r.sum().astype(jnp.int32),
-            delivered=((deliver_time >= 0).sum().astype(jnp.int32)
-                       + state.retired_delivered),
-            min_quack_prefix=min_qp.astype(jnp.int32),
-        )
+        with jax.named_scope("c3b_telemetry"):
+            qp = base + qprefix
+            min_qp = jnp.min(jnp.where(honest_s, qp, _BIG))
+            metrics = StepMetrics(
+                cross_msgs=(orig_ok.sum() + resend.sum()).astype(jnp.int32),
+                intra_msgs=jnp.einsum("jk,j->", bcast_sent.astype(jnp.int32),
+                                      reach.sum(axis=1).astype(jnp.int32)
+                                      ).astype(jnp.int32),
+                resends=resend.sum().astype(jnp.int32),
+                acks=alive_r.sum().astype(jnp.int32),
+                delivered=((deliver_time >= 0).sum().astype(jnp.int32)
+                           + state.retired_delivered),
+                min_quack_prefix=min_qp.astype(jnp.int32),
+            )
         return new_state, metrics
 
     return step
@@ -874,7 +893,9 @@ def _build_run(nspec: SimSpec):
         def step_obs(carry, t):
             s, mc = carry
             s2, ms = step(s, t)
-            return (s2, update_metrics(mc, s, s2, ms, t)), ms
+            with jax.named_scope("c3b_telemetry"):
+                mc = update_metrics(mc, s, s2, ms, t)
+            return (s2, mc), ms
 
         return jax.lax.scan(step_obs,
                             (state0, init_metrics_carry(nspec.m)), ts)
@@ -934,6 +955,9 @@ _CHUNK_TRACES = [0]
 # deterministically.
 _CHUNK_DISPATCHES = [0]
 _HOST_SYNCS = [0]
+# dispatches issued while the previous one was still undrained: how
+# often the async double buffering actually launched ahead
+_CHUNK_LAUNCH_AHEAD = [0]
 
 
 def chunk_trace_count() -> int:
@@ -944,6 +968,11 @@ def chunk_trace_count() -> int:
 def chunk_dispatch_count() -> int:
     """Device dispatches issued by the windowed engine so far."""
     return _CHUNK_DISPATCHES[0]
+
+
+def chunk_launch_ahead_count() -> int:
+    """Dispatches issued while the previous dispatch was undrained."""
+    return _CHUNK_LAUNCH_AHEAD[0]
 
 
 def host_sync_count() -> int:
@@ -1002,7 +1031,9 @@ def _build_chunk(nspec: SimSpec, w_slots: int, chunk_len: int, rotate: bool):
             def step_obs(c, t):
                 s, mcc = c
                 s2, ms = step(s, t)
-                return (s2, update_metrics(mcc, s, s2, ms, t)), ms
+                with jax.named_scope("c3b_telemetry"):
+                    mcc = update_metrics(mcc, s, s2, ms, t)
+                return (s2, mcc), ms
 
             (state, mc), ms = jax.lax.scan(step_obs, (state, mc), ts)
         else:
@@ -1012,21 +1043,26 @@ def _build_chunk(nspec: SimSpec, w_slots: int, chunk_len: int, rotate: bool):
                                state.retry, state.recv_has, base0,
                                jnp.zeros((), dtype=jnp.int32))
             if collect:
-                return (state, mc), ms, queue, snapshot_metrics(mc)
+                with jax.named_scope("c3b_telemetry"):
+                    blk = snapshot_metrics(mc)
+                return (state, mc), ms, queue, blk
             return state, ms, queue
-        f = gc_frontier_device(
-            base=base0, t_next=t0 + chunk_len, m=nspec.m,
-            known=state.known, bcast_q=state.bcast_q,
-            recv_has=state.recv_has, ack_floor=state.ack_floor,
-            stakes_r=fail.stakes_r, quack_thresh=fail.quack_thresh,
-            orig_sent=state.orig_sent, crash_r=fail.crash_r,
-            byz_ack_low=fail.byz_ack_low)
-        queue = ChunkQueue(state.quack_time, state.deliver_time,
-                           state.retry, state.recv_has, base0, f)
-        state = _rotate_device(state, f, w_slots)
+        with jax.named_scope("c3b_rotate"):
+            f = gc_frontier_device(
+                base=base0, t_next=t0 + chunk_len, m=nspec.m,
+                known=state.known, bcast_q=state.bcast_q,
+                recv_has=state.recv_has, ack_floor=state.ack_floor,
+                stakes_r=fail.stakes_r, quack_thresh=fail.quack_thresh,
+                orig_sent=state.orig_sent, crash_r=fail.crash_r,
+                byz_ack_low=fail.byz_ack_low)
+            queue = ChunkQueue(state.quack_time, state.deliver_time,
+                               state.retry, state.recv_has, base0, f)
+            state = _rotate_device(state, f, w_slots)
         if collect:
-            mc = rotate_metrics(mc, f, w_slots)
-            return (state, mc), ms, queue, snapshot_metrics(mc)
+            with jax.named_scope("c3b_telemetry"):
+                mc = rotate_metrics(mc, f, w_slots)
+                blk = snapshot_metrics(mc)
+            return (state, mc), ms, queue, blk
         return state, ms, queue
 
     return chunk
@@ -1403,7 +1439,7 @@ def _run_windowed_batch(specs: List[SimSpec], commit_floors=None, *,
     ``jax.device_get``) raises ``SanitizerError`` instead of silently
     serializing the pipeline.
     """
-    _tr = obs_begin()
+    _tr = obs_begin("run")
     try:
         if specs and specs[0].debug_checks:
             from ..analysis.sanitizer import engine_guard
@@ -1454,7 +1490,9 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
     is bit-identical to the K = 1 synchronous loop in outputs, metrics,
     frontier trajectories, growth events and recorded traces.
     ``chunk_dispatch_count`` / ``host_sync_count`` expose the ~K×
-    dispatch and sync reduction deterministically (``bench_pipeline``).
+    dispatch and sync reduction deterministically (``bench_pipeline``),
+    and ``chunk_launch_ahead_count`` how often a dispatch launched
+    ahead of the previous one's drain.
 
     ``commit_floors``, when given, is called as ``commit_floors(t, bases)``
     before the chunk starting at round ``t`` (``bases`` = each scenario's
@@ -1493,6 +1531,7 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
     available but the dense-layout fallback (O(M) state) raises instead
     of silently allocating the horizon.
     """
+    _tpro = obs_begin("prologue")
     spec0 = specs[0]
     n_b = len(specs)
     nspec = _neutral(spec0)
@@ -1623,7 +1662,7 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
         of the first unexecuted chunk; the loop re-enters there and
         takes the growth decision exactly where K = 1 would have."""
         nonlocal bases, t
-        _tw = obs_begin()
+        _tw = obs_begin("drain_wait")
         # one batched fetch per dispatch — the metrics blocks (when
         # collecting) ride the same device_get, zero extra transfers
         ms, queue, oks, blk = jax.device_get(
@@ -1632,6 +1671,7 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
         # concurrently with device compute (PR 5 double buffering)
         obs_end(_tw, "drain_wait", cat="drain", k=ent["k"],
                 overlapped=bool(pending))
+        _tu = obs_begin("unpack")
         _HOST_SYNCS[0] += 1
         k = ent["k"]
         executed = k if oks is None else int(np.asarray(oks).sum())
@@ -1655,8 +1695,7 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
                     obs_parts.append(bp)
             if not ent["rotate"]:
                 if not retain:
-                    drain_sink.on_chunk(ent["t0"] + (i + 1) * ent["c"],
-                                        msp, qp, bp, bases.copy())
+                    to_sink(ent["t0"] + (i + 1) * ent["c"], msp, qp, bp)
                 continue               # final chunk: nothing retired
             # the host's base mirror must track the in-graph rotation
             # exactly; the comparison is debug-gated so steady-state
@@ -1699,14 +1738,35 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
                 # sink instead of (B, ..., M) mirrors — O(B * W) per
                 # drain, independent of how far the stream has run
                 bases = bases + np.asarray(qp.count, dtype=np.int64)
-                drain_sink.on_chunk(ent["t0"] + (i + 1) * ent["c"],
-                                    msp, qp, bp, bases.copy())
+                to_sink(ent["t0"] + (i + 1) * ent["c"], msp, qp, bp)
+        obs_end(_tu, "unpack", cat="drain", k=k, executed=executed)
+
+    def to_sink(t_end: int, msp, qp, bp) -> None:
+        _ts = obs_begin("sink_chunk")
+        drain_sink.on_chunk(t_end, msp, qp, bp, bases.copy())
+        obs_end(_ts, "sink_chunk", cat="drain", t=t_end)
+
+    def why_sync(async_ok: bool, last: bool, t_next: int) -> Optional[str]:
+        """Why a dispatch is drained before the next one launches (None:
+        it may stay in flight)."""
+        if K == 1:
+            return "k1"
+        if not async_ok:
+            return "bound"
+        if last:
+            return "last"
+        if commit_floors is not None or (recorder is not None
+                                         and recorder.wants(t_next)):
+            return "host_boundary"
+        return None
 
     def drain_all() -> None:
         while pending:
             drain_one(pending.pop(0))
 
+    obs_end(_tpro, "prologue", cat="engine")
     while t < spec0.steps:
+        _tpl = obs_begin("plan")
         c = min(c_full, spec0.steps - t)
         # (a) failure-schedule swap: host-only work — the masks are
         # traced inputs, so a swap needs no device sync
@@ -1730,7 +1790,7 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
         if recorder is not None and recorder.wants(t):
             drain_all()
             _HOST_SYNCS[0] += 1
-            _tc = obs_begin()
+            _tc = obs_begin("checkpoint")
             recorder.capture(ChunkCheckpoint(
                 t=t, window_slots=w, bases=bases.copy(),
                 state=_np_state(_sim(carry)), fails=_np_state(fails),
@@ -1746,7 +1806,7 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
         # retired prefixes, so the pipeline drains before asking
         if commit_floors is not None:
             drain_all()
-            _tp = obs_begin()
+            _tp = obs_begin("plan_floors")
             new_floors = np.asarray(commit_floors(t, bases.copy()),
                                     dtype=np.int64)
             obs_end(_tp, "plan_floors", cat="plan", t=t)
@@ -1803,7 +1863,7 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
                         f"{suggest}) (or raise the slack in repro."
                         "stream.workload.stream_window_slots), or "
                         "lower the arrival rate")
-                _tg = obs_begin()
+                _tg = obs_begin("dense_migration")
                 sim_state = _migrate_dense_batch(
                     spec0, _sim(carry), bases, out_quack,
                     out_deliver, out_retry, out_recv)
@@ -1818,7 +1878,7 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
                 obs_end(_tg, "dense_migration", cat="window", t=t,
                         new_w=m)
             else:
-                _tg = obs_begin()
+                _tg = obs_begin("window_growth")
                 if collect:
                     carry = (_grow_state(carry[0], new_w),
                              pad_metrics(carry[1], new_w))
@@ -1855,10 +1915,13 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
         span_need = np.minimum(int(dispatched_by[t + k * c - 1]),
                                floors - 1)
         async_ok = K > 1 and bool((span_need - bases < w).all())
+        if _tpl is not None:
+            obs_end(_tpl, "plan", cat="plan", t=t, k=k, async_ok=async_ok,
+                    why_sync=why_sync(async_ok, last, t + k * c))
         # (f) dispatch, then drain the *previous* dispatch's queue while
         # this one computes (async double buffering; JAX dispatch is
         # asynchronous, so the call returns before the device finishes)
-        _td = obs_begin()
+        _td = obs_begin("dispatch")
         traces_before = _CHUNK_TRACES[0]
         blk = None
         if k == 1:
@@ -1878,6 +1941,8 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
             else:
                 carry, ms, queue, oks = res
         _CHUNK_DISPATCHES[0] += 1
+        if pending:
+            _CHUNK_LAUNCH_AHEAD[0] += 1
         obs_end(_td,
                 "compile" if _CHUNK_TRACES[0] > traces_before
                 else "dispatch",
@@ -1891,7 +1956,7 @@ def _run_windowed_batch_impl(specs: List[SimSpec], commit_floors=None, *,
             drain_all()   # sync regime (and the superchunk=1 legacy loop)
 
     drain_all()
-    _tf = obs_begin()
+    _tf = obs_begin("final_flush")
     got = jax.device_get(carry)        # one batched fetch, carry incl.
     final = _sim(got)                  # the metrics carry when enabled
     final_mc = got[1] if collect else None

@@ -8,18 +8,39 @@ stays green by construction).
 A :class:`SpanTracer` is installed for the dynamic extent of a run with
 :func:`tracing`; the engine's instrumentation points go through
 :func:`obs_begin` / :func:`obs_end`, which are no-ops (and take no
-clock samples) when no tracer is installed.
+clock samples) when no tracer is installed. While one is installed,
+each span begun with a name is also a ``jax.profiler.TraceAnnotation``
+of that name, so a profiled run shows the program's spans on the
+profiler's own clock, beside the device's operations. Those
+annotations must close in LIFO order on their thread: :func:`obs_end`
+first closes any annotation begun inside the span that was left open
+(an exception skipped its ``obs_end``).
 
 Canonical span names emitted by the engine
-(``tests/test_obs.py`` asserts these):
+(``tests/test_obs.py`` and ``tests/test_stream.py`` assert these):
 
-  ``run``             whole ``_run_windowed_batch`` invocation
+  ``run``             whole ``_run_windowed_batch`` invocation; its
+                      children below cover all of it
+  ``prologue``        entry to the first loop iteration: spec checks,
+                      host mirrors, stacked failure masks, initial carry
+  ``plan``            loop top up to the dispatch: failure-schedule
+                      swap, floors, overflow check, the choice of ``k``;
+                      args ``t``, ``k``, ``async_ok`` and ``why_sync``
+                      (why the dispatch is drained before the next one
+                      launches: ``k1``, ``bound``, ``last``,
+                      ``host_boundary``; None when it may launch ahead)
   ``compile``         a dispatch that traced at least one new program
   ``dispatch``        enqueue of an already-compiled chunk/superchunk
+                      (the profiler names both ``dispatch``; a compile
+                      carries ``span=compile`` there)
   ``drain_wait``      blocking ``device_get`` of a dispatch's queue;
                       ``args.overlapped`` is True when the fetched
                       dispatch had a successor already in flight
                       (PR 5 double buffering doing its job)
+  ``unpack``          the rest of a drain: per-chunk split of the
+                      fetched queue and metrics, base bookkeeping
+  ``sink_chunk``      one ``drain_sink.on_chunk`` call (inside
+                      ``unpack``; horizon mode only)
   ``plan_floors``     topology commit-floor planning callback
   ``checkpoint``      recorder snapshot capture
   ``window_growth``   adaptive 2x window growth (state re-pad)
@@ -36,10 +57,13 @@ The PR 5 async double buffering becomes a first-class number via
 from __future__ import annotations
 
 import json
+import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span",
@@ -48,6 +72,7 @@ __all__ = [
     "SpanTracer",
     "tracing",
     "current_tracer",
+    "OpenSpan",
     "obs_begin",
     "obs_end",
     "obs_span",
@@ -278,23 +303,68 @@ def tracing(tracer: SpanTracer):
         _CURRENT[0] = prev
 
 
-def obs_begin() -> Optional[int]:
-    """Timestamp for a prospective span; None (no clock sample) when
-    tracing is disabled."""
+@dataclass
+class OpenSpan:
+    """A span begun by :func:`obs_begin`, handed back to :func:`obs_end`."""
+
+    name: Optional[str]
+    begin_ns: int
+    annotation: Optional[TraceAnnotation] = None
+
+
+_OPEN = threading.local()      # per thread: annotations entered, in order
+
+
+def _annotations() -> List[TraceAnnotation]:
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    return stack
+
+
+def _close(annotation: TraceAnnotation) -> None:
+    """Exit ``annotation`` and, first, every one entered after it that is
+    still open, so the profiler's annotations always nest; one already
+    closed that way is left alone."""
+    stack = _annotations()
+    for i in range(len(stack) - 1, -1, -1):
+        if stack[i] is annotation:
+            for inner in reversed(stack[i:]):
+                inner.__exit__(None, None, None)
+            del stack[i:]
+            return
+
+
+def obs_begin(name: Optional[str] = None) -> Optional[OpenSpan]:
+    """Begin a prospective span; None (no clock sample) when tracing is
+    disabled. With ``name`` the span is a profiler annotation as well."""
     tr = _CURRENT[0]
-    return tr.begin() if tr is not None else None
+    if tr is None:
+        return None
+    annotation = None
+    if name is not None:
+        annotation = TraceAnnotation(name)
+        annotation.__enter__()
+        _annotations().append(annotation)
+    return OpenSpan(name, tr.begin(), annotation)
 
 
-def obs_end(begin_ns: Optional[int], name: str, cat: str = "host",
+def obs_end(span: Optional[OpenSpan], name: str, cat: str = "host",
             **args: Any) -> None:
+    if span is None:
+        return
+    if span.annotation is not None:
+        if name != span.name:      # named at its end (compile/dispatch)
+            span.annotation.set_metadata(span=name)
+        _close(span.annotation)
     tr = _CURRENT[0]
-    if tr is not None and begin_ns is not None:
-        tr.end(begin_ns, name, cat, **args)
+    if tr is not None:
+        tr.end(span.begin_ns, name, cat, **args)
 
 
 @contextmanager
 def obs_span(name: str, cat: str = "host", **args: Any):
-    b = obs_begin()
+    b = obs_begin(name)
     try:
         yield
     finally:

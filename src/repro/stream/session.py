@@ -24,6 +24,7 @@ off.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -32,7 +33,8 @@ from typing import List, Optional
 import numpy as np
 
 from ..core.simulator import (SimSpec, _run_windowed_batch,
-                              chunk_dispatch_count, chunk_trace_count,
+                              chunk_dispatch_count,
+                              chunk_launch_ahead_count, chunk_trace_count,
                               host_sync_count, spec_with_failures)
 from ..core.types import NetworkModel, RSMConfig, SimConfig
 from ..obs.live import (LatencySketch, LiveAggregator, LiveReport,
@@ -98,10 +100,10 @@ class StreamResult:
     live: LiveReport
     slo_events: List[SLOEvent]
     capacity: dict                    # offered/sustained vs analytic
-    counters: dict                    # dispatches/traces/syncs deltas
+    counters: dict                    # dispatches/traces/syncs/
+                                      # launched_ahead deltas
     final_window_slots: int
     growth_events: tuple
-    spans: dict
     problems: List[str]               # live-vs-device invariant breaks
 
     def percentiles(self, qs=(50, 95, 99)) -> dict:
@@ -296,11 +298,14 @@ class StreamSession:
             from ..topology.engine import FloorPlanner
             commit_floors = FloorPlanner.chain(n_lanes, self.spec.m,
                                                keep_history=False)
-        tracer = tracer or SpanTracer()
-        t0, d0, s0 = (chunk_trace_count(), chunk_dispatch_count(),
-                      host_sync_count())
+        # spans go to ``tracer``, else to whichever tracer is installed
+        # around the call (none: the engine's hooks stay no-ops)
+        traced = (tracing(tracer) if tracer is not None
+                  else contextlib.nullcontext())
+        t0, d0, s0, a0 = (chunk_trace_count(), chunk_dispatch_count(),
+                          host_sync_count(), chunk_launch_ahead_count())
         try:
-            with tracing(tracer):
+            with traced:
                 out = _run_windowed_batch(specs,
                                           commit_floors=commit_floors,
                                           fail_schedule=schedule,
@@ -311,6 +316,7 @@ class StreamSession:
         counters = {"traces": chunk_trace_count() - t0,
                     "dispatches": chunk_dispatch_count() - d0,
                     "syncs": host_sync_count() - s0,
+                    "launched_ahead": chunk_launch_ahead_count() - a0,
                     "chunks_drained": sink.chunks,
                     "live_rows": report.total_rows}
 
@@ -339,8 +345,7 @@ class StreamSession:
             live=report, slo_events=list(watchdog.events),
             capacity=cap, counters=counters,
             final_window_slots=sink.final_w,
-            growth_events=sink.growth_events,
-            spans=tracer.to_dict(), problems=problems)
+            growth_events=sink.growth_events, problems=problems)
 
     @staticmethod
     def _validate(agg: LiveAggregator, obs: List[ObsMetrics]) -> List[str]:

@@ -329,6 +329,69 @@ def test_tracer_spans_and_chrome_schema():
     assert obs_begin() is None
 
 
+def test_span_annotations_close_in_lifo_order():
+    """Each named span is a profiler annotation while a tracer is
+    installed; ending an outer span first closes any inner one an
+    exception left open, so the annotations always nest."""
+    from repro.obs.tracer import _annotations, obs_begin, obs_end
+
+    tr = SpanTracer()
+    with tracing(tr):
+        outer = obs_begin("outer")
+        obs_begin("abandoned")          # its obs_end never runs
+        inner = obs_begin("inner")
+        assert len(_annotations()) == 3
+        obs_end(outer, "outer")
+        assert _annotations() == []
+        obs_end(inner, "inner")         # already closed: recorded only
+        renamed = obs_begin("dispatch")
+        obs_end(renamed, "compile")
+    assert tr.names() == ["outer", "inner", "compile"]
+    assert _annotations() == []
+    assert obs_begin("x") is None       # no tracer: nothing begins
+
+
+def test_program_spans_land_on_the_profiler_clock(tmp_path):
+    """With the JAX profiler on, the engine's spans are host events of
+    the trace, nested as the tracer recorded them."""
+    import glob
+
+    import jax
+
+    from repro.core.simulator import _run_windowed_batch
+    from repro.stream import ArrivalProcess, build_stream_spec
+
+    spec = build_stream_spec(
+        RSMConfig.bft(1), RSMConfig.bft(1),
+        SimConfig(window=1, phi=6, window_slots="auto", chunk_steps=8,
+                  superchunk=4),
+        ArrivalProcess(kind="constant", rate=4.0), 128)
+
+    class Sink:
+        def on_chunk(self, *a):
+            pass
+
+        def on_final(self, *a):
+            pass
+
+    _run_windowed_batch([spec], drain_sink=Sink())      # compile first
+    tr = SpanTracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing(tr):
+            _run_windowed_batch([spec], drain_sink=Sink())
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                     recursive=True)[0]
+    data = jax.profiler.ProfileData.from_file(path)
+    seen = [ev.name for p in data.planes for ln in p.lines
+            for ev in ln.events]
+    for name in ("run", "prologue", "plan", "dispatch", "drain_wait",
+                 "unpack", "sink_chunk", "final_flush"):
+        assert seen.count(name) == tr.count(name), name
+
+
 def test_validate_chrome_trace_rejects_malformed():
     assert validate_chrome_trace([]) != []
     assert validate_chrome_trace({}) != []

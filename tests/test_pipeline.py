@@ -20,8 +20,9 @@ import pytest
 
 from repro.core import FailureScenario, RSMConfig, SimConfig
 from repro.core.quack import stake_quorum_bitmap
-from repro.core.simulator import (build_spec, run_simulation,
-                                  run_simulation_batch)
+from repro.core.simulator import (build_spec, chunk_dispatch_count,
+                                  chunk_launch_ahead_count, run_simulation,
+                                  run_simulation_batch, superchunk_program)
 
 BFT1 = RSMConfig.bft(1)
 
@@ -135,7 +136,58 @@ def test_async_drain_overlap_engages():
                  window_slots=128, chunk_steps=4)
     s1 = _spec(simkw, FailureScenario.none(), 1)
     s4 = _spec(simkw, FailureScenario.none(), 4)
-    _assert_same(run_simulation(s1), run_simulation(s4))
+    a0 = chunk_launch_ahead_count()
+    r1 = run_simulation(s1)
+    a1 = chunk_launch_ahead_count()
+    r4 = run_simulation(s4)
+    _assert_same(r1, r4)
+    # the counter tells the overlap path from the sync regime: K = 1
+    # never launches ahead, the fused run does
+    assert a1 - a0 == 0
+    assert chunk_launch_ahead_count() - a1 > 0
+
+
+def test_launch_ahead_counter_matches_the_plan():
+    """Each launch-ahead is one drain that overlapped its successor; a
+    plan that names a reason to sync (K = 1, or a span the conservative
+    bound cannot clear) is never followed by one."""
+    from repro.obs.tracer import SpanTracer, tracing
+
+    simkw = dict(n_msgs=256, steps=256 // 4 + 40, window=1, phi=6,
+                 window_slots=32, chunk_steps=4)
+    for k in (1, 4):
+        tr = SpanTracer()
+        a0 = chunk_launch_ahead_count()
+        with tracing(tr):
+            run_simulation(_spec(simkw, FailureScenario.none(), k))
+        ahead = chunk_launch_ahead_count() - a0
+        plans = [sp.args for sp in tr.spans if sp.name == "plan"]
+        overlapped = [sp for sp in tr.spans if sp.name == "drain_wait"
+                      and sp.args["overlapped"]]
+        assert ahead == len(overlapped)
+        assert len(plans) == tr.count("dispatch") + tr.count("compile")
+        # dispatch j launches ahead only if plan j - 1 named no reason
+        assert ahead <= sum(p["why_sync"] is None for p in plans[:-1])
+        if k == 1:      # the sync regime: nothing ever launches ahead
+            assert ahead == 0
+            assert {p["why_sync"] for p in plans} == {"k1"}
+        else:
+            assert "bound" in {p["why_sync"] for p in plans}
+            assert plans[-1]["why_sync"] == "last"
+
+
+def test_step_phases_named_in_program_metadata():
+    """The protocol step's phases reach the compiled program's HLO
+    metadata as named scopes, so a device trace can attribute time to
+    them whatever fusion numbers a compile hands out."""
+    simkw = dict(n_msgs=64, steps=64 // 4 + 40, window=1, phi=6,
+                 window_slots=32, chunk_steps=4)
+    program, args = superchunk_program(
+        _spec(simkw, FailureScenario.none(), 2), lanes=2)
+    text = program.lower(*args).as_text(debug_info=True)
+    for scope in ("c3b_retransmit", "c3b_quorum", "c3b_broadcast",
+                  "c3b_send", "c3b_hq", "c3b_ack", "c3b_rotate"):
+        assert scope in text, scope
 
 
 def test_debug_checks_off_still_exact():

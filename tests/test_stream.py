@@ -33,8 +33,9 @@ from repro.core.simulator import (_run_windowed_batch,
                                   spec_with_failures)
 from repro.obs.live import (LatencySketch, LiveSample, SLOConfig,
                             SLOWatchdog)
-from repro.obs.metrics import (MetricsBlock, delta_metrics_block,
-                               merge_metrics_blocks, zero_metrics_block)
+from repro.obs.metrics import (NUM_LATENCY_BUCKETS, MetricsBlock,
+                               delta_metrics_block, merge_metrics_blocks,
+                               zero_metrics_block)
 from repro.obs.report import validate_chrome_trace
 from repro.obs.tracer import SpanTracer
 from repro.stream import (ArrivalProcess, StreamConfig, StreamSession,
@@ -189,10 +190,13 @@ try:
         fields = {}
         for name in MetricsBlock._fields:
             if name == "latency_hist":
+                # the fabric's histogram width, so a drawn block merges
+                # with zero_metrics_block()
                 fields[name] = np.asarray(
                     draw(st.lists(st.integers(0, 1 << 40),
-                                  min_size=1, max_size=1).map(
-                        lambda v: v * 8)), dtype=np.int64)
+                                  min_size=NUM_LATENCY_BUCKETS,
+                                  max_size=NUM_LATENCY_BUCKETS)),
+                    dtype=np.int64)
             else:
                 fields[name] = np.int64(draw(st.integers(0, 1 << 40)))
         return MetricsBlock(**fields)
@@ -245,6 +249,68 @@ def test_session_live_equals_posthoc_and_zero_extra_dispatches():
     assert res.sketch.total() == 512
     assert res.retired == 512
     assert res.capacity["sustained_frac"] > 0
+
+
+def _nest(spans):
+    """(outer, inner) pairs; raises unless every two spans either nest
+    or are disjoint (the order profiler annotations need)."""
+    pairs = []
+    for a in spans:
+        for b in spans:
+            if a is b:
+                continue
+            a0, a1 = a.start_ns, a.start_ns + a.dur_ns
+            b0, b1 = b.start_ns, b.start_ns + b.dur_ns
+            if a0 <= b0 and b1 <= a1 and (a0, -a1) < (b0, -b1):
+                pairs.append((a.name, b.name))
+            elif a0 < b0 < a1 < b1:
+                raise AssertionError(f"{a.name} and {b.name} overlap")
+    return pairs
+
+
+def test_session_spans_cover_the_host_loop():
+    """In a 2-lane session every host-loop span the engine emits sits
+    inside ``run``: one ``prologue``, a ``plan`` per dispatch, an
+    ``unpack`` per drain, a ``sink_chunk`` per drained chunk; the
+    ``dispatch`` spans keep their args; and the spans nest, as the
+    profiler's annotations must."""
+    from repro.obs.tracer import _annotations
+
+    session = StreamSession(BFT1, BFT1, _sim(),
+                            StreamConfig(horizon=512, links=2))
+    session.run()                                   # compile first
+    tr = SpanTracer()
+    res = session.run(tracer=tr)
+    c = res.counters
+    assert tr.count("run") == tr.count("prologue") == 1
+    launches = tr.count("dispatch") + tr.count("compile")
+    assert tr.count("plan") == launches == c["dispatches"] > 1
+    assert tr.count("unpack") == tr.count("drain_wait") == launches
+    assert tr.count("sink_chunk") == c["chunks_drained"] > launches
+    assert c["launched_ahead"] == sum(
+        s.args["overlapped"] for s in tr.spans if s.name == "drain_wait")
+    for s in tr.spans:
+        if s.name == "dispatch":
+            assert set(s.args) == {"t", "k"}
+        if s.name == "plan":
+            assert set(s.args) == {"t", "k", "async_ok", "why_sync"}
+    host = [s for s in tr.spans if s.cat != "slo"]
+    pairs = set(_nest(host))
+    for name in ("prologue", "plan", "dispatch", "drain_wait", "unpack",
+                 "sink_chunk", "final_flush"):
+        assert ("run", name) in pairs, name
+    assert ("unpack", "sink_chunk") in pairs
+    assert _annotations() == []         # every annotation closed
+    # the spans under ``run`` leave little of it unnamed
+    run = next(s for s in tr.spans if s.name == "run")
+    named, end = 0, run.start_ns
+    for s in sorted((s for s in host if s.name != "run"),
+                    key=lambda s: s.start_ns):
+        lo, hi = max(s.start_ns, end), s.start_ns + s.dur_ns
+        if hi > lo:
+            named += hi - lo
+            end = hi
+    assert named > 0.8 * run.dur_ns
 
 
 def test_sink_mode_contract_validation():
