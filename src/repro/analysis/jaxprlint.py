@@ -105,16 +105,27 @@ class ProgramAudit:
 def iter_eqns(jaxpr):
     """Yield every equation of ``jaxpr``, descending into sub-jaxprs
     (pjit bodies, scan bodies, cond branches, custom_* calls...)."""
-    for eqn in jaxpr.eqns:
+    for _, eqn in iter_scoped_eqns(jaxpr):
         yield eqn
+
+
+def iter_scoped_eqns(jaxpr, scope: str = ""):
+    """:func:`iter_eqns` yielding ``(scope, eqn)``, where ``scope`` is the
+    equation's whole name stack (its ``jax.named_scope``s, ``/``-joined):
+    a sub-jaxpr's equations carry only the part of the stack inside it,
+    so the enclosing equations' part is prefixed."""
+    for eqn in jaxpr.eqns:
+        here = "/".join(p for p in (scope, str(eqn.source_info.name_stack))
+                        if p)
+        yield here, eqn
         for val in eqn.params.values():
             vals = val if isinstance(val, (tuple, list)) else (val,)
             for item in vals:
                 sub = getattr(item, "jaxpr", None)
                 if sub is not None:              # ClosedJaxpr
-                    yield from iter_eqns(sub)
+                    yield from iter_scoped_eqns(sub, here)
                 elif hasattr(item, "eqns"):      # raw Jaxpr
-                    yield from iter_eqns(item)
+                    yield from iter_scoped_eqns(item, here)
 
 
 def _device_constants(closed) -> List[Tuple[int, ...]]:

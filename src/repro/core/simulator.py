@@ -598,6 +598,54 @@ def _neutral(spec: SimSpec) -> SimSpec:
         superchunk=1, debug_checks=False)
 
 
+def _take_static(table: Tuple[int, ...], pos):
+    """``table[pos]`` for a compile-time table of ints and ``0 <= pos <
+    len(table)``, with no gather: a TPU runs a gather of per-slot indices
+    one index at a time, these are elementwise. The identity table (unit
+    stakes) is ``pos`` itself; any other is a select over its positions."""
+    # ``table`` is a tuple of the spec, static at trace time
+    if table == tuple(range(len(table))):  # analysis: ignore[tracer-branch]
+        return pos
+    out = jnp.full_like(pos, table[0])
+    for p, v in enumerate(table[1:], 1):
+        out = jnp.where(pos == p, v, out)
+    return out
+
+
+def _retransmit_lookups(spec: SimSpec, drop_pair, abs_idx, orig_recv, retry):
+    """Each (sender, slot)'s retransmission at its retry count, read with
+    no gather: whether that sender is the elected one (n_s, W), the
+    one-hot of the receiver it goes to (n_s, n_r, W), and whether the
+    pair's link drops it (n_s, W).
+
+    Fig. 6: the a-th retransmission of k is sent by the a-th successor of
+    the original sender, ``rs_seq[(k + a) mod len]``, to ``rr_seq[(orig
+    recv + a) mod len]``; the rotations are static, the drops traced.
+    """
+    idx_s = jnp.arange(spec.n_s, dtype=jnp.int32)
+    idx_r = jnp.arange(spec.n_r, dtype=jnp.int32)
+    pos_s = (abs_idx[None, :] + retry) % len(spec.rs_seq)
+    pos_r = (orig_recv[None, :] + retry) % len(spec.rr_seq)
+    elected = _take_static(spec.rs_seq, pos_s) == idx_s[:, None]
+    target = _take_static(spec.rr_seq, pos_r)
+    hot = target[:, None, :] == idx_r[None, :, None]
+    drop = (hot & drop_pair[:, :, None]).any(axis=1)
+    return elected, hot, drop
+
+
+def _send_lookups(may_send, drop_pair, orig_sender, orig_recv):
+    """Each slot's original send read with no gather, through the one-hots
+    of its sender (n_s, W) and receiver (n_r, W): whether that sender may
+    send (W,) and whether the pair's link drops it (W,)."""
+    n_s, n_r = drop_pair.shape
+    s_hot = orig_sender[None, :] == jnp.arange(n_s, dtype=jnp.int32)[:, None]
+    r_hot = orig_recv[None, :] == jnp.arange(n_r, dtype=jnp.int32)[:, None]
+    ok = (s_hot & may_send[:, None]).any(axis=0)
+    drop = (s_hot[:, None, :] & r_hot[None, :, :]
+            & drop_pair[:, :, None]).any(axis=(0, 1))
+    return s_hot, r_hot, ok, drop
+
+
 def _protocol_step(spec: SimSpec, fail: FailArrays, sched_w, base, w: int):
     """Per-round transition over ``w`` window columns starting at ``base``.
 
@@ -613,9 +661,6 @@ def _protocol_step(spec: SimSpec, fail: FailArrays, sched_w, base, w: int):
     # compiled program serves every stake re-weighting / membership swap
     stakes_s = fail.stakes_s
     stakes_r = fail.stakes_r
-    rs_seq = jnp.asarray(spec.rs_seq, dtype=jnp.int32)
-    rr_seq = jnp.asarray(spec.rr_seq, dtype=jnp.int32)
-    ls, lr = len(spec.rs_seq), len(spec.rr_seq)
 
     abs_idx = (base + jnp.arange(w, dtype=jnp.int32)).astype(jnp.int32)
     idx_r = jnp.arange(n_r, dtype=jnp.int32)
@@ -656,18 +701,13 @@ def _protocol_step(spec: SimSpec, fail: FailArrays, sched_w, base, w: int):
             # bit (not the schedule round) is what proves that.
             declared = lost_prev & state.orig_sent[None, :]
             retry_new = state.retry + declared.astype(jnp.int32)
-            # Fig. 6: the a-th retransmission of k is sent by the a-th
-            # successor of the original sender:
-            # sender_new = (orig + #retransmit) mod n_s.
-            elected = (rs_seq[(abs_idx[None, :] + retry_new) % ls]
-                       == idx_s[:, None])
+            elected, re_hot, drop_re = _retransmit_lookups(
+                spec, fail.drop_pair, abs_idx, orig_recv, retry_new)
             resend = (declared & elected & alive_s[:, None]
                       & ~fail.byz_send_drop[:, None])
             # clear complaint trackers where a loss was declared (fresh cycle)
             complaint = jnp.where(declared[:, None, :], False, state.complaint)
             repeat_c = jnp.where(declared[:, None, :], False, state.repeat_c)
-            re_target = rr_seq[(orig_recv[None, :] + retry_new)
-                               % lr]                          # (n_s, W)
             # adversary: an equivocating sender's retransmissions carry a
             # payload conflicting with the original — receivers detect the
             # mismatch and discard them wholesale (no store, no ack claim,
@@ -676,7 +716,6 @@ def _protocol_step(spec: SimSpec, fail: FailArrays, sched_w, base, w: int):
             # so the election keeps rotating toward an honest retransmitter.
             # Selective per-pair drops kill the copy in the network instead:
             # same observable non-delivery, but scoped to (sender, receiver).
-            drop_re = jnp.take_along_axis(fail.drop_pair, re_target, axis=1)
             resend_land = resend & ~fail.byz_equiv_send[:, None] & ~drop_re
 
         # (3) original sends + landing --------------------------------------
@@ -688,19 +727,19 @@ def _protocol_step(spec: SimSpec, fail: FailArrays, sched_w, base, w: int):
             # semantics where a crashed sender's message is simply never sent.
             due = ((orig_step <= t) & (abs_idx < fail.commit_floor)
                    & ~state.orig_sent)
-            orig_ok = (due & alive_s[orig_sender]
-                       & ~fail.byz_send_drop[orig_sender])
-            orig_sent = state.orig_sent | due
+            # whether each slot's original sender may send, and the
             # selective drop of the original copy: the (orig sender, orig
             # receiver) pair is dropped in the network after being sent
-            drop_o = fail.drop_pair[orig_sender, orig_recv]          # (W,)
+            s_hot, r_hot, may_send, drop_o = _send_lookups(
+                alive_s & ~fail.byz_send_drop, fail.drop_pair,
+                orig_sender, orig_recv)
+            orig_ok = due & may_send
+            orig_sent = state.orig_sent | due
             orig_land = orig_ok & ~drop_o
-            s_orig = (orig_land[None, :]
-                      & (orig_recv[None, :] == idx_r[:, None]))
+            s_orig = orig_land[None, :] & r_hot
         with jax.named_scope("c3b_retransmit"):
-            s_re = (jnp.einsum("lm,lim->im", resend_land.astype(jnp.int32),
-                               (re_target[:, None, :] == idx_r[None, :, None])
-                               .astype(jnp.int32)) > 0)
+            re_land = re_hot & resend_land[:, None, :]      # (n_s, n_r, W)
+            s_re = re_land.any(axis=0)
         with jax.named_scope("c3b_send"):
             wire = s_orig | s_re                                   # (n_r, W)
             land = wire & alive_r[:, None] & ~fail.byz_recv_drop[:, None]
@@ -717,15 +756,11 @@ def _protocol_step(spec: SimSpec, fail: FailArrays, sched_w, base, w: int):
         # absolute prefix is base + the in-window prefix.
         with jax.named_scope("c3b_hq"):
             qp_prev = base + qprefix_prev
-            e_lk = ((orig_sender[None, :] == idx_s[:, None])
-                    & orig_land[None, :])                          # (n_s, W)
+            e_lk = s_hot & orig_land[None, :]                      # (n_s, W)
             sent_orig_to = jnp.einsum("lk,ik->li", e_lk.astype(jnp.int32),
                                       s_orig.astype(jnp.int32)) > 0
         with jax.named_scope("c3b_retransmit"):
-            sent_re_to = jnp.einsum(
-                "lm,lim->li", resend_land.astype(jnp.int32),
-                (re_target[:, None, :] == idx_r[None, :, None])
-                .astype(jnp.int32)) > 0
+            sent_re_to = re_land.any(axis=2)                       # (n_s, n_r)
         with jax.named_scope("c3b_hq"):
             heard = (sent_orig_to | sent_re_to).T                  # (n_r, n_s)
             # adversary: an hq-lying sender inflates its piggybacked prefix
