@@ -9,8 +9,10 @@ later change, and a break of the configuration's ``quack_quorum``
 guarantee), is put in the program's place: its sessions go through the
 same comparison a run's sessions go through, against the sound
 reference, with the lanes sampled from each seed as a run samples them.
-The check must come out not correct. The benchmark's own runs never run
-this; it prints one JSON line per seed with every number compared.
+The check must come out not correct. Where the configuration carries
+failures, both references run under the seed's crash placement, as a
+run's check does. The benchmark's own runs never run this; it prints
+one JSON line per seed with every number compared.
 """
 
 import argparse
@@ -25,7 +27,7 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from bench import compare  # noqa: E402
-from bench.harness import reference  # noqa: E402
+from bench.harness import placement, reference  # noqa: E402
 from bench.window import SessionRun  # noqa: E402
 from bench.workload import arrivals, drain_rounds, find_cell  # noqa: E402
 
@@ -40,8 +42,10 @@ def readings(cell, seed: int) -> dict:
     ref = reference(cell.config)
     kw = dict(links=cell.links, chained=cell.chained, steps=steps,
               chunk=cell.chunk_steps)
-    sound = ref.run(cell.config, counts, **kw)
-    short = ref.run(cell.config, counts, quorum_short=1, **kw)
+    failures = placement(cell, seed)
+    crashes = {} if failures is None else {"failures": failures}
+    sound = ref.run(cell.config, counts, **kw, **crashes)
+    short = ref.run(cell.config, counts, quorum_short=1, **kw, **crashes)
     rng = np.random.default_rng(seed)
     lanes = [int(rng.integers(cell.links)) for _ in range(SESSIONS)]
     offered = cell.horizon * cell.links

@@ -9,6 +9,9 @@ runs whole sessions back to back and starts none once ``seconds`` have
 passed. With ``trace`` the first window session runs under the JAX
 profiler. After the window the program's state is dropped, the plain
 reference runs once, and every window session is compared with it.
+Where the configuration carries failures, set-up draws the crash
+placement from the seed once and gives the same placement to the
+program and to the reference.
 """
 
 from __future__ import annotations
@@ -17,6 +20,7 @@ import dataclasses
 import gc
 import importlib
 import importlib.util
+import inspect
 import sys
 import time
 from pathlib import Path
@@ -26,7 +30,8 @@ import numpy as np
 
 from bench import compare, devtrace
 from bench.window import SessionRun, run_one, tapped
-from bench.workload import ROOT, Cell, arrivals, build_session, drain_rounds
+from bench.workload import (ROOT, Cell, arrivals, build_session,
+                            crash_placement, drain_rounds)
 
 METRICS = Path(__file__).resolve().parent / "metrics"
 TRACE_DIR = ROOT / ".bench_out" / "trace"
@@ -92,10 +97,25 @@ class Context:
         return plain or self.runs
 
 
+def placement(cell: Cell, seed: int) -> Optional[dict]:
+    """The seed's crash placement of the cell's configuration (None
+    without failures); a crashed run is never checked against a
+    reference that models no failure."""
+    failures = crash_placement(cell.config, seed)
+    if failures is not None and "failures" not in inspect.signature(
+            reference(cell.config).run).parameters:
+        raise ValueError(
+            f"configuration {cell.config['name']!r} carries failures but "
+            f"its reference {cell.config['reference']!r} models none")
+    return failures
+
+
 def prepare(cell: Cell, seed: int):
-    """The cell's session, after checking it offers the generated
-    traffic for the rounds the reference will run."""
-    session = build_session(cell)
+    """The cell's session under the seed's crash placement, after
+    checking it offers the generated traffic for the rounds the
+    reference will run."""
+    failures = placement(cell, seed)
+    session = build_session(cell, failures)
     counts = arrivals(cell.traffic, seed)
     steps = len(counts) + drain_rounds(cell.config, cell.traffic)
     if not np.array_equal(np.asarray(session.arrivals), counts):
@@ -104,7 +124,7 @@ def prepare(cell: Cell, seed: int):
     if session.spec.steps != steps:
         raise ValueError(f"the program runs {session.spec.steps} rounds "
                          f"a session; the benchmark expects {steps}")
-    return session, counts, steps
+    return session, counts, steps, failures
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
@@ -118,13 +138,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     # cache every program, however quick to compile, so a run's set-up
     # after the first finds all of them there
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    session, counts, steps = prepare(cell, seed)
+    session, counts, steps, failures = prepare(cell, seed)
     spec = session.spec
     log(f"cell {cell.name}: n={spec.n_s}<->{spec.n_r} links={cell.links} "
         f"chained={cell.chained} horizon={spec.m} rate="
         f"{session.config.process.rate} steps={steps} chunk="
         f"{spec.chunk_steps} W={spec.window_slots} superchunk="
         f"{spec.superchunk} compile_cache={cache}")
+    if failures is not None:
+        log(f"crash placement: crash_s={list(failures['crash_s'])} "
+            f"crash_r={list(failures['crash_r'])}")
     rng = np.random.default_rng(seed)
     pick = lambda: int(rng.integers(cell.links))  # noqa: E731
     runs: List[SessionRun] = []
@@ -162,9 +185,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     t_ref = time.monotonic()
     ref_mod = reference(cell.config)
+    crashes = {} if failures is None else {"failures": failures}
     lanes = ref_mod.run(cell.config, counts, links=cell.links,
                         chained=cell.chained, steps=steps,
-                        chunk=cell.chunk_steps)
+                        chunk=cell.chunk_steps, **crashes)
     ref_for = lambda lane: ref_mod.as_record(  # noqa: E731
         lanes, links=cell.links, chained=cell.chained,
         chunk=cell.chunk_steps, steps=steps, sample_lane=lane)

@@ -6,6 +6,15 @@ network, the protocol's widths, the yardstick window and the guarantees)
 and a traffic mix (``bench/traffic/<traffic>.json``: arrivals, horizon,
 links, chaining, chunk length). Nothing here knows a cell by name, so a
 later cell is a new traffic file and a new entry, with no code edited.
+
+A configuration may carry crash-stop failures::
+
+    "failures": {"kind": "crash_stop", "crash_s": 6, "crash_r": 6,
+                 "at_round": 0}
+
+that many replicas of the sending and of the receiving RSM are down from
+``at_round`` on; which ones, the seed draws (:func:`crash_placement`).
+Without the key no replica crashes.
 """
 
 from __future__ import annotations
@@ -110,9 +119,44 @@ def drain_rounds(config: dict, traffic: dict) -> int:
         + 2 * config["protocol"]["phi"] + 96
 
 
-def build_session(cell: Cell):
-    """The program's ``StreamSession`` for ``cell``, as a user builds it."""
-    from repro.core import RSMConfig, SimConfig
+# the stream of the seed's draws that places crashes, apart from the
+# default stream the harness draws the check's lanes from
+PLACEMENT_STREAM = 1
+
+
+def crash_placement(config: dict, seed: int) -> Optional[dict]:
+    """The crash round of each replica of each RSM (-1: never) under the
+    configuration's ``failures``, or None where it has none.
+
+    Returns ``{"crash_s": (n_s,) rounds, "crash_r": (n_r,) rounds}``:
+    the replicas that crash are drawn from ``seed`` by a generator of
+    their own, so the lanes the check samples from ``seed`` are the same
+    with or without failures.
+    """
+    spec = config.get("failures")
+    if spec is None:
+        return None
+    if spec.get("kind") != "crash_stop":
+        raise ValueError(f"unknown failure kind {spec.get('kind')!r}")
+    at = int(spec["at_round"])
+    if at < 0:
+        raise ValueError(f"failures.at_round {at} is before round 0")
+    rng = np.random.default_rng([seed, PLACEMENT_STREAM])
+    out = {}
+    for side, key in (("sender", "crash_s"), ("receiver", "crash_r")):
+        n, k = int(config[side]["n"]), int(spec[key])
+        if not 0 <= k <= n:
+            raise ValueError(f"failures.{key} = {k} of {n} replicas")
+        rounds = np.full(n, -1, np.int64)
+        rounds[rng.choice(n, size=k, replace=False)] = at
+        out[key] = tuple(int(x) for x in rounds)
+    return out
+
+
+def build_session(cell: Cell, failures: Optional[dict] = None):
+    """The program's ``StreamSession`` for ``cell``, as a user builds it,
+    with the crash placement ``failures`` (:func:`crash_placement`)."""
+    from repro.core import FailureScenario, RSMConfig, SimConfig
     from repro.core.types import NetworkModel
     from repro.stream import ArrivalProcess, StreamConfig, StreamSession
 
@@ -128,5 +172,7 @@ def build_session(cell: Cell):
                                rate=float(traffic["arrivals"]["rate"])),
         net=NetworkModel(**conf["network"]), links=cell.links,
         chained=cell.chained, report_every=int(traffic["report_every"]))
+    crashes = None if failures is None else FailureScenario(
+        crash_s=failures["crash_s"], crash_r=failures["crash_r"])
     return StreamSession(rsm(conf["sender"]), rsm(conf["receiver"]), sim,
-                         cfg)
+                         cfg, failures=crashes)
